@@ -475,8 +475,8 @@ def mesh_rows():
     client per pod, FSDP weights, mb data-sharded.  Final losses of the two
     rows are asserted bitwise-equal (ISSUE 4 acceptance) and pinned into the
     JSON as <name>.final_loss next to the round times; --guard covers the
-    _scan rows.  cross_silo rather than cross_device because the latter's
-    partial-manual shard_map needs the jax>=0.6 stack (DESIGN §8)."""
+    _scan rows.  cross_silo is the regime whose clients each hold a model
+    sharded over several devices (DESIGN §8)."""
     if jax.device_count() < 8:
         if GUARD:
             # never let the guarded CI step go green without its rows: if
@@ -788,4 +788,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -17,9 +17,11 @@ from repro.core.safl import SAFLConfig, init_safl, safl_round, \
     uplink_bits_per_round
 from repro.core.sketch import SketchConfig
 from repro.data import BigramLMData, LMDataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.driver import run_scan
 from repro.models import ModelConfig, init_params, loss_fn
 
+enable_compile_cache()
 model = ModelConfig(name="tiny", arch_type="dense", num_layers=2, d_model=64,
                     num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=128)
 safl = SAFLConfig(

@@ -53,6 +53,7 @@ from repro.data import BigramLMData, LMDataConfig
 from repro.fed import AsyncConfig, CodecConfig, FaultConfig, \
     SentinelConfig, UniformParticipation, init_async_state, \
     init_codec_state, make_async_round
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.driver import run_scan
 from repro.launch.supervisor import SupervisorConfig, format_recovery_log, \
     run_supervised
@@ -105,6 +106,7 @@ ap.add_argument("--resume", action="store_true",
                 "round index, so the resumed run is bit-identical to an "
                 "uninterrupted one (tests/test_resume.py)")
 args = ap.parse_args()
+enable_compile_cache()
 
 if args.big:  # ~100M (paper's BERT scale)
     model = ModelConfig(name="lm100m", arch_type="dense", num_layers=12,

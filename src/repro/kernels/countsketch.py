@@ -56,11 +56,13 @@ def _countsketch_kernel(x_ref, h_ref, o_ref, *, b_block: int):
     cols = (jax.lax.broadcasted_iota(jnp.int32, (tile_n, b_block), 1)
             + pl.program_id(1) * b_block)
     onehot = (h.reshape(tile_n, 1) == cols).astype(x.dtype)   # (TILE_N, b_block)
-    o_ref[...] += jnp.dot(x, onehot, preferred_element_type=jnp.float32)
+    # HIGHEST: an f32 sum, not one over x rounded to bf16
+    o_ref[...] += jnp.dot(x, onehot, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def countsketch_clients_pallas(x: jax.Array, h: jax.Array, b: int, *,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """Batched count-sketch ``out[g, j] = sum_{h[i]==j} x[g, i]``.
 
     x: (G, n) float32 (already sign-multiplied), h: (n,) int32 in [0, b),
@@ -94,7 +96,7 @@ def countsketch_clients_pallas(x: jax.Array, h: jax.Array, b: int, *,
 
 
 def countsketch_pallas(x: jax.Array, h: jax.Array, b: int, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Count-sketch ``segment_sum(x, h, b)`` via the batched Pallas kernel.
 
     x: (n,) float32 (already sign-multiplied), h: (n,) int32 in [0, b).

@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_N = 512  # input elements per grid step; (TILE_N, b) tile of R in VMEM
 
@@ -34,8 +35,10 @@ def _splitmix32(x: jax.Array) -> jax.Array:
 
 
 def _uniform01(bits: jax.Array) -> jax.Array:
-    # top 24 bits -> (0, 1]; never exactly 0 so log() is safe
-    return ((bits >> jnp.uint32(8)).astype(jnp.float32) + 1.0) * (2.0 ** -24)
+    # top 24 bits -> (0, 1]; never exactly 0 so log() is safe.  The 24-bit
+    # value fits int32 exactly, and Mosaic converts int32 (not uint32) to f32
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return (top.astype(jnp.float32) + 1.0) * (2.0 ** -24)
 
 
 def _gauss_tile(seed: jax.Array, tile: jax.Array, tile_n: int, b: int) -> jax.Array:
@@ -61,18 +64,20 @@ def _sk_kernel(seed_ref, x_ref, o_ref, *, b: int):
 
     rt = _gauss_tile(seed_ref[0], i, TILE_N, b)           # (TILE_N, b)
     x = x_ref[...]                                        # (1, TILE_N)
-    o_ref[...] += jnp.dot(x, rt, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(x, rt, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def _desk_kernel(seed_ref, s_ref, o_ref, *, b: int):
     i = pl.program_id(0)
     rt = _gauss_tile(seed_ref[0], i, TILE_N, b)           # (TILE_N, b)
     s = s_ref[...]                                        # (1, b)
-    o_ref[...] = jnp.dot(s, rt.T, preferred_element_type=jnp.float32)
+    o_ref[...] = jnp.dot(s, rt.T, preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
 
 
 def gaussian_sk_pallas(seed: jax.Array, x: jax.Array, b: int, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """sk(x) = R x / sqrt(b) with R regenerated tile-by-tile in-kernel."""
     n = x.shape[0]
     n_pad = ((n + TILE_N - 1) // TILE_N) * TILE_N
@@ -81,7 +86,7 @@ def gaussian_sk_pallas(seed: jax.Array, x: jax.Array, b: int, *,
         functools.partial(_sk_kernel, b=b),
         grid=(n_pad // TILE_N,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # scalar seed, whole array
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # scalar seed
             pl.BlockSpec((1, TILE_N), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, b), lambda i: (0, 0)),
@@ -92,7 +97,7 @@ def gaussian_sk_pallas(seed: jax.Array, x: jax.Array, b: int, *,
 
 
 def gaussian_desk_pallas(seed: jax.Array, s: jax.Array, n: int, *,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """desk(s) = R^T s / sqrt(b), regenerating the same R tiles as sk."""
     b = s.shape[0]
     n_pad = ((n + TILE_N - 1) // TILE_N) * TILE_N
@@ -101,7 +106,7 @@ def gaussian_desk_pallas(seed: jax.Array, s: jax.Array, n: int, *,
         functools.partial(_desk_kernel, b=b),
         grid=(n_pad // TILE_N,),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, b), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, TILE_N), lambda i: (0, i)),

@@ -1,8 +1,10 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on a
-real TPU backend they compile through Mosaic.  ``repro.core.sketch`` routes
-through these when ``SketchConfig.use_pallas`` is set.
+``_interpret`` is the one place that decides interpret mode: on any backend
+other than a TPU the kernels run in the Pallas interpreter; on a TPU they
+compile through Mosaic.  The kernel functions themselves take ``interpret``
+with no default.  ``repro.core.sketch`` routes through these when
+``SketchConfig.use_pallas`` is set.
 """
 
 from __future__ import annotations

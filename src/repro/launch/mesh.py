@@ -10,14 +10,11 @@ import jax
 from jax.sharding import Mesh
 
 
-def _mesh(shape, axes) -> Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there
-    # anyway, so on older jax we simply omit the kwarg.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _mesh(shape, axes, devices=None) -> Mesh:
+    """``devices`` defaults to ``jax.devices()``; pass a described topology's
+    devices to compile for a chip that is not attached."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

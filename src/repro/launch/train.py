@@ -50,37 +50,7 @@ from repro.models.config import ModelConfig
 from repro.models.model import decode_step, forward, loss_fn, param_shapes
 from repro.models.sharding import param_pspecs
 
-try:  # jax>=0.6 moved shard_map to the top level (axis_names/check_vma API)
-    _shard_map_impl = jax.shard_map
-    _NEW_SHARD_MAP = True
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl  # type: ignore
-    _NEW_SHARD_MAP = False
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=False):
-    """Version shim: call sites use the new-jax kwargs; on jax 0.4.x we
-    translate axis_names (manual axes) to the old ``auto`` complement and
-    check_vma to check_rep."""
-    if _NEW_SHARD_MAP:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kw)
-    kw = {"check_rep": check_vma}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map_impl(f, mesh, in_specs, out_specs, **kw)
-
 Pytree = Any
-
-# Test hook: force the jax-0.4.x cross_device client-delta formulation (the
-# vmap fallback below) on the new stack too, so its bitwise parity against
-# the partial-manual shard_map path can be asserted where both compile
-# (tests/test_mesh_scan.py).
-_FORCE_VMAP_CLIENT_DELTAS = False
 
 
 def data_axes_of(mesh) -> tuple[str, ...]:
@@ -368,9 +338,9 @@ def sharded_sketch_avg_desk(mesh, skcfg: SketchConfig, pspecs, deltas, key,
             # mean over it otherwise)
             return jax.tree.map(lambda u: u.mean(axis=0), upd)
 
-        return shard_map(local, mesh=mesh,
-                         in_specs=(in_specs, P()), out_specs=out_specs,
-                         check_vma=False)(deltas, key)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(in_specs, P()), out_specs=out_specs,
+                             check_vma=False)(deltas, key)
 
     w = mask_weights(part_mask)                              # (G,)
     den = float(part_mask["den"]) if is_weighted_mask(part_mask) else None
@@ -379,9 +349,9 @@ def sharded_sketch_avg_desk(mesh, skcfg: SketchConfig, pspecs, deltas, key,
         upd = fn(d, k, wl, den)         # leaves (1, ...): the cohort mean
         return jax.tree.map(lambda u: u.mean(axis=0), upd)
 
-    return shard_map(local_masked, mesh=mesh,
-                     in_specs=(in_specs, P(), P(lead)), out_specs=out_specs,
-                     check_vma=False)(deltas, key, w)
+    return jax.shard_map(local_masked, mesh=mesh,
+                         in_specs=(in_specs, P(), P(lead)),
+                         out_specs=out_specs, check_vma=False)(deltas, key, w)
 
 
 def _sharded_sketch_guarded(mesh, plan: PackingPlan, pspecs, deltas, key,
@@ -451,9 +421,9 @@ def _sharded_sketch_guarded(mesh, plan: PackingPlan, pspecs, deltas, key,
     if fault_spec is not None:
         args.append(fault_spec)
         specs.append({k: P() for k in fault_spec})
-    return shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=(pspecs, P(), P()),
-                     check_vma=False)(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=(pspecs, P(), P()),
+                         check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -493,36 +463,10 @@ def client_deltas_sharded(model_cfg: ModelConfig, safl_cfg: SAFLConfig, mesh,
         # the pod axis of a 3-axis mesh (hard CHECK failure); the vmap
         # formulation partitions cleanly here because the client count (2
         # pods) matches the pod axis exactly and weights carry no pod axis.
-        with manual_axes(()):
-            def one(mb):
-                return client_delta(safl_cfg, loss, params, mb, eta)
-            deltas, losses = jax.vmap(one)(batch)
-        return deltas, losses
-
-    if not _NEW_SHARD_MAP or _FORCE_VMAP_CLIENT_DELTAS:
-        # jax 0.4.x: the partial-manual shard_map below hard-crashes the
-        # bundled XLA (IsManualSubgroup CHECK) as soon as a sharding hint
-        # appears inside the manual region.  The cross_silo-style vmap
-        # formulation runs the SAME per-client program -- identical
-        # fold_in/grad/reduction chain per client, clients independent, G
-        # sharded over the client axes by GSPMD instead of manually -- so
-        # trajectories match the shard_map path bitwise (asserted on the
-        # new stack, where both compile, by tests/test_mesh_scan.py); this
-        # is what lets the full mesh suite run on both jax stacks
-        # (ROADMAP: cross_device scan on jax 0.4.x).
-        vmap_haxes = ()
-        if topology == "cross_device_dp":
-            # the in-body hint (mb data-parallel over the model axis) moves
-            # outside the vmap: same spec, one leading G dim earlier; model-
-            # axis hints stay disabled so GSPMD can propagate batch-over-
-            # model freely, exactly like the shard_map body
-            vmap_haxes = ("model",)
-            batch = jax.tree.map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, P(*((caxes, None, "model")
-                           + (None,) * (x.ndim - 3)))) if x.ndim >= 3 else x,
-                batch)
-        with manual_axes(vmap_haxes):
+        # The vmapped G dim already sits on the client axes, so the model's
+        # batch hints must not claim them too: with them, a (pod, data=1,
+        # model) mesh aborts XLA's SPMD partitioner (device-group CHECK).
+        with manual_axes(caxes):
             def one(mb):
                 return client_delta(safl_cfg, loss, params, mb, eta)
             deltas, losses = jax.vmap(one)(batch)
@@ -531,10 +475,10 @@ def client_deltas_sharded(model_cfg: ModelConfig, safl_cfg: SAFLConfig, mesh,
     lead = P(caxes)
     b_specs = jax.tree.map(lambda x: lead, batch)
     d_specs = jax.tree.map(lambda x: lead, params)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), b_specs),
-                     out_specs=(d_specs, lead),
-                     axis_names=set(caxes), check_vma=False)(params, batch)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), b_specs),
+                         out_specs=(d_specs, lead),
+                         axis_names=set(caxes), check_vma=False)(params, batch)
 
 
 def _mesh_pspecs(model_cfg: ModelConfig, topology: str):
@@ -750,8 +694,8 @@ def sharded_sketch_buffered(mesh, acfg, plan: PackingPlan, pspecs, deltas,
     if fault_spec is not None:
         args.append(fault_spec)
         specs.append({k: P() for k in fault_spec})
-    return shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=out_specs, check_vma=False)(*args)
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def _make_round_core(model_cfg: ModelConfig, safl_cfg: SAFLConfig, mesh,

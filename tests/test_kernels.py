@@ -1,4 +1,8 @@
-"""Per-kernel Pallas (interpret=True) vs pure-jnp/numpy oracle sweeps."""
+"""Per-kernel Pallas (interpret=True) vs pure-jnp/numpy oracle sweeps.
+
+The kernel functions take ``interpret`` with no default; these tests pass
+``interpret=True`` themselves (the TPU compile of the same kernels is
+checked in tests/test_tpu_compile.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +23,7 @@ def test_countsketch_shapes(n, b):
     rng = np.random.RandomState(n + b)
     x = rng.randn(n).astype(np.float32)
     h = rng.randint(0, b, n).astype(np.int32)
-    got = countsketch_pallas(jnp.array(x), jnp.array(h), b)
+    got = countsketch_pallas(jnp.array(x), jnp.array(h), b, interpret=True)
     want = ref.countsketch_ref(jnp.array(x), jnp.array(h), b)
     np.testing.assert_allclose(np.array(got), np.array(want),
                                rtol=1e-4, atol=1e-4)
@@ -30,7 +34,8 @@ def test_countsketch_dtypes(dtype):
     rng = np.random.RandomState(0)
     x = rng.randn(333).astype(dtype)
     h = rng.randint(0, 64, 333).astype(np.int32)
-    got = countsketch_pallas(jnp.asarray(x, jnp.float32), jnp.array(h), 64)
+    got = countsketch_pallas(jnp.asarray(x, jnp.float32), jnp.array(h), 64,
+                             interpret=True)
     want = ref.countsketch_ref(jnp.asarray(x, jnp.float32), jnp.array(h), 64)
     np.testing.assert_allclose(np.array(got), np.array(want), atol=1e-4)
 
@@ -55,7 +60,8 @@ def test_countsketch_clients_batched(g, n, b):
     rng = np.random.RandomState(g + n)
     x = rng.randn(g, n).astype(np.float32)
     h = rng.randint(0, b, n).astype(np.int32)
-    got = countsketch_clients_pallas(jnp.array(x), jnp.array(h), b)
+    got = countsketch_clients_pallas(jnp.array(x), jnp.array(h), b,
+                                     interpret=True)
     want = np.stack([np.array(ref.countsketch_ref(jnp.array(x[i]),
                                                   jnp.array(h), b))
                      for i in range(g)])
@@ -73,7 +79,7 @@ def test_countsketch_clients_jit_wrapper():
 @pytest.mark.parametrize("shape", [(1, 8), (3, 64), (20, 512), (9, 4096)])
 def test_fwht_rows(shape):
     x = np.random.RandomState(1).randn(*shape).astype(np.float32)
-    got = fwht_rows_pallas(jnp.array(x))
+    got = fwht_rows_pallas(jnp.array(x), interpret=True)
     want = ref.fwht_ref(x)
     np.testing.assert_allclose(np.array(got), want, rtol=1e-3, atol=1e-2)
 
@@ -81,7 +87,7 @@ def test_fwht_rows(shape):
 @pytest.mark.parametrize("n", [64, 4096, 8192, 32768])
 def test_fwht_1d_including_kronecker_path(n):
     x = np.random.RandomState(2).randn(n).astype(np.float32)
-    got = fwht_pallas(jnp.array(x))
+    got = fwht_pallas(jnp.array(x), interpret=True)
     want = ref.fwht_ref(x)
     np.testing.assert_allclose(np.array(got), want, rtol=1e-3, atol=0.2)
 
@@ -98,7 +104,7 @@ def test_fwht_involution():
     """H (H x) = n x."""
     n = 1024
     x = np.random.RandomState(3).randn(n).astype(np.float32)
-    y = fwht_pallas(fwht_pallas(jnp.array(x)))
+    y = fwht_pallas(fwht_pallas(jnp.array(x), interpret=True), interpret=True)
     np.testing.assert_allclose(np.array(y) / n, x, rtol=1e-3, atol=1e-3)
 
 
@@ -106,7 +112,7 @@ def test_fwht_involution():
 def test_gaussian_sk_matches_ref(n, b):
     x = np.random.RandomState(4).randn(n).astype(np.float32)
     seed = jnp.array(11, jnp.uint32)
-    got = gaussian_sk_pallas(seed, jnp.array(x), b)
+    got = gaussian_sk_pallas(seed, jnp.array(x), b, interpret=True)
     want = ref.gaussian_sk_ref(11, x, b)
     np.testing.assert_allclose(np.array(got), want, rtol=1e-3, atol=1e-3)
 
@@ -115,7 +121,7 @@ def test_gaussian_sk_matches_ref(n, b):
 def test_gaussian_desk_matches_ref(n, b):
     s = np.random.RandomState(5).randn(b).astype(np.float32)
     seed = jnp.array(11, jnp.uint32)
-    got = gaussian_desk_pallas(seed, jnp.array(s), n)
+    got = gaussian_desk_pallas(seed, jnp.array(s), n, interpret=True)
     want = ref.gaussian_desk_ref(11, s, n)
     np.testing.assert_allclose(np.array(got), want, rtol=1e-3, atol=1e-3)
 
@@ -127,8 +133,10 @@ def test_gaussian_adjointness():
     v = rng.randn(n).astype(np.float32)
     s = rng.randn(b).astype(np.float32)
     seed = jnp.array(42, jnp.uint32)
-    lhs = float(np.array(gaussian_sk_pallas(seed, jnp.array(v), b)) @ s)
-    rhs = float(v @ np.array(gaussian_desk_pallas(seed, jnp.array(s), n)))
+    sk = gaussian_sk_pallas(seed, jnp.array(v), b, interpret=True)
+    desk = gaussian_desk_pallas(seed, jnp.array(s), n, interpret=True)
+    lhs = float(np.array(sk) @ s)
+    rhs = float(v @ np.array(desk))
     assert abs(lhs - rhs) < 1e-2 * max(1.0, abs(lhs))
 
 

@@ -22,11 +22,6 @@ suite, so when this module is collected on a single-device session it
 re-runs itself in a subprocess with
 ``--xla_force_host_platform_device_count=8`` (the mini-dry-run pattern);
 CI additionally runs the direct tests in a dedicated 8-device job step.
-Both topologies run on BOTH jax stacks: on jax 0.4.x (whose bundled XLA
-hard-crashes on the partial-manual client-delta shard_map,
-IsManualSubgroup CHECK) cross_device routes through the vmap fallback,
-which the new stack pins bitwise against the shard_map formulation
-(test_vmap_fallback_matches_shard_map).
 """
 
 import os
@@ -62,12 +57,9 @@ from repro.models import ModelConfig, init_params
 from repro.models.sharding import use_mesh
 
 ON_8 = jax.device_count() >= 8
-NEW_SHARD_MAP = hasattr(jax, "shard_map")   # partial-manual needs jax>=0.6
 
 needs8 = pytest.mark.skipif(not ON_8, reason="needs 8 forced CPU devices")
 
-# both topologies run on both jax stacks: 0.4.x takes the cross_device vmap
-# fallback (launch/train.py, DESIGN §9) instead of partial-manual shard_map
 TOPOLOGIES = [
     pytest.param("cross_silo", id="cross_silo"),
     pytest.param("cross_device", id="cross_device"),
@@ -610,32 +602,6 @@ def test_mesh_buffer_guards():
                           participation=UniformParticipation(16, frac=0.5))
 
 
-@needs8
-@pytest.mark.skipif(not NEW_SHARD_MAP,
-                    reason="the shard_map side of the parity pair needs "
-                           "jax>=0.6 (0.4.x always takes the fallback)")
-def test_vmap_fallback_matches_shard_map():
-    """The jax-0.4.x cross_device client-delta fallback (vmap over the
-    client axis instead of partial-manual shard_map) is bitwise-identical
-    to the shard_map formulation -- asserted on the new stack, where both
-    compile.  This is what justifies running the whole mesh suite on both
-    stacks (DESIGN §9)."""
-    topology = "cross_device"
-    mesh, cfg, smp = _mk(topology)
-    key = jax.random.key(42)
-    with use_mesh(mesh):
-        p1, o1, h1 = run_mesh_scan(MODEL, cfg, mesh, smp, *_fresh(cfg),
-                                   rounds=2, key=key, topology=topology)
-        train_mod._FORCE_VMAP_CLIENT_DELTAS = True
-        try:
-            p2, o2, h2 = run_mesh_scan(MODEL, cfg, mesh, smp, *_fresh(cfg),
-                                       rounds=2, key=key, topology=topology)
-        finally:
-            train_mod._FORCE_VMAP_CLIENT_DELTAS = False
-    np.testing.assert_array_equal(h1["loss"], h2["loss"])
-    _assert_trees_equal((p1, o1), (p2, o2))
-
-
 # ---------------------------------------------------------------------------
 # faults + sentinels on the mesh (ISSUE 7, DESIGN §10)
 # ---------------------------------------------------------------------------
@@ -835,7 +801,7 @@ def test_mesh_scan_suite_on_8_forced_devices_subprocess():
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
            # the device-count flag only affects the CPU backend: pin it so a
            # GPU machine cannot land back on < 8 devices and recurse
-           "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+           "JAX_PLATFORMS": "cpu",
            "MESH_SCAN_NO_SUBPROCESS": "1"}
     r = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q",

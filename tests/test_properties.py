@@ -177,14 +177,15 @@ def test_importance_mask_invariants(n, seed, t, raw):
     pol = ImportanceParticipation(n, probs=probs, frac=m / n, seed=seed)
     assert pol.cohort_size == m
     tt = jnp.asarray(t, jnp.int32)
-    m = pol.mask(tt)
-    w = np.asarray(m["w"])
+    mask = pol.mask(tt)
+    w = np.asarray(mask["w"])
     sel = w > 0
-    assert int(sel.sum()) == pol.cohort_size == m["n"]
-    assert m["den"] == float(pol.cohort_size)
+    assert int(sel.sum()) == pol.cohort_size == mask["n"]
+    assert mask["den"] == float(pol.cohort_size)
     np.testing.assert_allclose(
         w[sel], (1.0 / (n * np.asarray(probs, np.float64)))[sel], rtol=1e-5)
-    m2 = ImportanceParticipation(n, probs=probs, frac=0.5,
+    # the same policy, built again
+    m2 = ImportanceParticipation(n, probs=probs, frac=m / n,
                                  seed=seed).mask(tt)
     np.testing.assert_array_equal(w, np.asarray(m2["w"]))
 

@@ -136,22 +136,21 @@ MINI_DRYRUN = textwrap.dedent("""
 """)
 
 
-# no jax-version gate anymore: on 0.4.x (whose XLA hard-crashes on sharding
-# hints inside a partial-manual region, IsManualSubgroup CHECK) the
-# cross_device client deltas take the vmap fallback (DESIGN §9), so the
-# distributed step compiles on both stacks
 def test_mini_dryrun_8_devices():
     """Distributed SAFL train + serve lower AND compile on an 8-device host
     mesh (subprocess so the device-count flag never leaks into this test
     session)."""
     import os
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
-    if "JAX_PLATFORMS" in os.environ:  # keep the CPU pin; without it the
-        env["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]  # subprocess may
-        # spend minutes probing an absent TPU backend before falling back
+    env = {"PYTHONPATH": "src",
+           "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+           "HOME": os.environ.get("HOME", ""),
+           # the forced device count is a CPU-backend flag; pinning the child
+           # to the CPU also keeps it off an accelerator the parent may hold
+           "JAX_PLATFORMS": "cpu"}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run([sys.executable, "-c", MINI_DRYRUN],
                        capture_output=True, text=True, timeout=900,
-                       env=env, cwd="/root/repo")
+                       env=env, cwd=repo)
     assert "MINI_DRYRUN_OK" in r.stdout, r.stderr[-3000:]
 
 
