@@ -1,0 +1,7 @@
+"""Puts the repository root on ``sys.path`` so ``bench`` imports as a package."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
