@@ -17,6 +17,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs import spans
+
 Pytree = Any
 
 
@@ -49,6 +51,7 @@ def init_opt_state(cfg: AdaConfig, params: Pytree) -> dict:
     return state
 
 
+@jax.named_scope(spans.SERVER_OPT)
 def apply_update(cfg: AdaConfig, state: dict, params: Pytree, update: Pytree,
                  lr_scale: jax.Array | float = 1.0) -> tuple[Pytree, dict]:
     """One ADA_OPT step.  ``update`` is the (pseudo-)gradient direction

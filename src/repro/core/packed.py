@@ -49,6 +49,7 @@ from repro.core.sketch import (SketchConfig, _balanced_cs_params,
                                _cs_hashes, _gaussian_desk, _gaussian_sk,
                                _keys, _srht_params, fwht, leaf_sketch_size,
                                next_pow2)
+from repro.obs import spans
 
 Pytree = Any
 
@@ -225,6 +226,7 @@ def derive_generation_params(plan: PackingPlan, base_key: jax.Array,
     return derive_round_params(plan, jax.random.fold_in(base_key, g))
 
 
+@jax.named_scope(spans.DERIVE)
 def derive_round_params(plan: PackingPlan, key: jax.Array) -> dict:
     """Derive the round's sketch operator ONCE.
 
@@ -409,11 +411,13 @@ def sk_packed(plan: PackingPlan, rp: dict, tree: Pytree) -> jax.Array:
     return sk_flat(plan, rp, pack_tree(plan, tree))
 
 
+@jax.named_scope(spans.DESK)
 def desk_packed(plan: PackingPlan, rp: dict, payload: jax.Array) -> Pytree:
     """Desketch the (b_total,) payload back to the plan's pytree."""
     return unpack_tree(plan, desk_flat(plan, rp, payload))
 
 
+@jax.named_scope(spans.SKETCH)
 def sk_packed_clients(plan: PackingPlan, rp: dict, stacked: Pytree) -> jax.Array:
     """Sketch G stacked client trees (leaves (G, ...)) -> (G, b_total).
 
@@ -448,7 +452,8 @@ def sk_packed_clients_wsum(plan: PackingPlan, rp: dict, stacked: Pytree,
     across mesh client shards -- reproduces the cohort mean aggregation.
     """
     s = sk_packed_clients(plan, rp, stacked).astype(jnp.float32)
-    return jnp.sum(s * w[:, None].astype(s.dtype), axis=0), jnp.sum(w)
+    with jax.named_scope(spans.MEAN):
+        return jnp.sum(s * w[:, None].astype(s.dtype), axis=0), jnp.sum(w)
 
 
 def roundtrip_packed(plan: PackingPlan, key: jax.Array, tree: Pytree) -> Pytree:

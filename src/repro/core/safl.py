@@ -37,6 +37,7 @@ from repro.core.adaptive import AdaConfig, apply_update, init_opt_state
 from repro.core.packed import (derive_round_params, desk_packed,
                                make_packing_plan, sk_packed_clients)
 from repro.core.sketch import SketchConfig
+from repro.obs import spans
 
 Pytree = Any
 LossFn = Callable[[Pytree, Any], jax.Array]  # (params, batch) -> scalar loss
@@ -142,6 +143,7 @@ def masked_where_tree(mask, new: Pytree, old: Pytree) -> Pytree:
     return jax.tree.map(sel, new, old)
 
 
+@jax.named_scope(spans.CLIENT)
 def client_delta(cfg: SAFLConfig, loss_fn: LossFn, params: Pytree,
                  microbatches: Pytree, eta: jax.Array) -> tuple[Pytree, jax.Array]:
     """K local SGD steps for ONE client; returns (x_0 - x_K, mean local loss).
@@ -347,8 +349,9 @@ def streamed_sketch_round(cfg: SAFLConfig, client_fn, params: Pytree,
                 sks = jnp.where(ok[:, None], sks, jnp.float32(0.0))
                 n_rej = n_rej + jnp.sum((w > 0) & ~ok)
                 w = w * ok.astype(jnp.float32)
-            out = (S + jnp.sum(sks * w[:, None], axis=0), W + jnp.sum(w),
-                   L + jnp.sum(w * losses), n_rej)
+            with jax.named_scope(spans.MEAN):
+                out = (S + jnp.sum(sks * w[:, None], axis=0), W + jnp.sum(w),
+                       L + jnp.sum(w * losses), n_rej)
             if codec is not None:
                 out += (carry[4] + jnp.sum((w > 0).astype(jnp.float32)),)
             return out, ef_c
@@ -388,16 +391,18 @@ def streamed_sketch_round(cfg: SAFLConfig, client_fn, params: Pytree,
             # streams the SAME (decoded) payloads
             sks, _, _, _ = chunk_payload(xc)
             clean = jnp.where(xc["ok"][:, None], sks, jnp.float32(0.0))
-            return S + jnp.sum(clean * xc["we"][:, None], axis=0), None
+            with jax.named_scope(spans.MEAN):
+                return S + jnp.sum(clean * xc["we"][:, None], axis=0), None
 
         S, _ = jax.lax.scan(accum, S0, xs2)
         W = jnp.sum(w_eff)
         L = jnp.sum(w_eff * losses_p)
 
-    den = (jnp.asarray(part_mask["den"], jnp.float32)
-           if isinstance(part_mask, dict) else jnp.maximum(W, 1.0))
-    mbar = S / den
-    loss = L / den
+    with jax.named_scope(spans.MEAN):
+        den = (jnp.asarray(part_mask["den"], jnp.float32)
+               if isinstance(part_mask, dict) else jnp.maximum(W, 1.0))
+        mbar = S / den
+        loss = L / den
 
     update = desk_packed(plan, rp, mbar)
     new_params, new_opt = apply_update(cfg.server, opt_state, params, update,
@@ -526,7 +531,8 @@ def safl_round(cfg: SAFLConfig, loss_fn: LossFn, params: Pytree,
     # collective, and it moves b_total floats, not d.  Under partial
     # participation only the sampled cohort contributes, and the mean
     # divides by the cohort size, not N. ---
-    mbar = masked_mean(sketches, part_mask)
+    with jax.named_scope(spans.MEAN):
+        mbar = masked_mean(sketches, part_mask)
 
     # --- desk back to R^d and run ADA_OPT (Alg. 2); deterministic, so every
     # replica/client replays the identical server step. ---

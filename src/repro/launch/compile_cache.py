@@ -20,7 +20,16 @@ DEFAULT_CACHE_DIR = REPO_ROOT / ".jax_cache"
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent cache on; return the directory it uses."""
+    """Turn the persistent cache on; return the directory it uses.
+
+    JAX leaves debug info out of the cache key by default, so a program
+    that differs only in its stage scopes (``repro.obs.spans``) would load
+    an executable compiled without them, and a profile of it would name no
+    stage.  The key keeps the op names here; locations carry no source file
+    or line, so the key still holds when a checkout moves or a line shifts.
+    """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

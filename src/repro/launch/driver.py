@@ -55,6 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import spans
+from repro.obs.shards import host_fetch
 from repro.obs.telemetry import PROBE_KEYS
 
 Pytree = Any
@@ -164,7 +166,8 @@ def make_chunk_fn(round_fn: RoundFn, sampler, num_rounds: int, *,
     def chunk(params, state, data_state, key, t0):
         def body(carry, t):
             params, state, dstate = carry
-            dstate, batch = sampler.sample(dstate, t)
+            with jax.named_scope(spans.SAMPLE):
+                dstate, batch = sampler.sample(dstate, t)
             kw, mask = round_hook_kwargs(t, key, kwargs_fn, participation,
                                          buffer, faults)
             params, state, m = round_fn(params, state, batch,
@@ -248,6 +251,20 @@ def run_scan(round_fn: RoundFn, sampler, params: Pytree, state: dict, *,
       is ``{}`` and the shard files are the record.  ``on_chunk`` still
       receives each chunk's host-side history either way.
 
+    **Profile names** (``repro.obs.spans``, DESIGN.md §11).  Each chunk
+    opens three host spans on the profiler's clock:
+    ``run_scan.dispatch(t0=, rounds=, compile=)`` around the call into the
+    chunk (``compile=1`` on the first call of a chunk length, which holds
+    the compile), ``run_scan.fetch`` around the history's device->host copy
+    and ``run_scan.on_chunk`` around the callback.  With ``stream=``, the
+    span event in ``events.jsonl`` covers dispatch and fetch, timed from
+    the same two edges.  Inside the chunk, the round's stages carry the
+    ``jax.named_scope`` names ``safl.client``, ``safl.derive``,
+    ``safl.sketch``, ``safl.mean``, ``safl.desk``, ``safl.server_opt``,
+    and the batch draw ``driver.sample``, set where each stage's function
+    is defined.  Scopes are op metadata only: no program family, unlike a
+    ``Telemetry`` config; the spans cost nothing without a profiler.
+
     Returns ``(params, state, history)`` with ``history`` a dict of
     host-side ``(rounds - start_round,)`` arrays.  ``loss`` is always
     present; ``uplink_bits`` when ``bits_per_round`` is set; the
@@ -273,20 +290,23 @@ def run_scan(round_fn: RoundFn, sampler, params: Pytree, state: dict, *,
                 participation=participation, buffer=buffer, faults=faults,
                 microbatch=microbatch, codec=codec)
         t_wall = time.perf_counter()
-        params, state, data_state, hist = compiled[n](
-            params, state, data_state, key, jnp.asarray(t, jnp.int32))
+        with spans.span(spans.DISPATCH, t0=t, rounds=n, compile=int(fresh)):
+            params, state, data_state, hist = compiled[n](
+                params, state, data_state, key, jnp.asarray(t, jnp.int32))
+        with spans.span(spans.FETCH):          # ONE fetch per chunk
+            hist = (host_fetch(hist) if stream is not None  # async copy
+                    else jax.tree.map(np.asarray, hist))
         if stream is not None:
-            from repro.obs.shards import host_fetch
-            hist = host_fetch(hist)            # async copy, ONE fetch
+            # the interval of the two spans above, from their two edges
             dt = time.perf_counter() - t_wall
             stream.write_chunk(t, hist)
             stream.write_span(t, t + n, dt, compile=fresh)
         else:
-            hist = jax.tree.map(np.asarray, hist)  # ONE fetch per chunk
             hists.append(hist)
         t += n
         if on_chunk is not None:
-            on_chunk(t, params, state, hist)
+            with spans.span(spans.ON_CHUNK):
+                on_chunk(t, params, state, hist)
     if not hists:   # streamed, or resumed at start_round == rounds
         return params, state, {}
     history = jax.tree.map(lambda *xs: np.concatenate(xs), *hists)

@@ -1,20 +1,13 @@
-"""Profiling report over a telemetry run directory (DESIGN.md §11).
+"""Report over a telemetry run directory (DESIGN.md §11).
 
 ``render(run_dir)`` loads the manifest, metric shards and event log written
-by ``obs.shards``/``obs.manifest`` and produces a text report with four
-sections:
+by ``obs.shards``/``obs.manifest`` and produces a text report with three
+sections, each read from the run directory alone:
 
 1. manifest summary (stack versions, backend, config, guard pins count),
 2. metric summary (rounds, final/mean loss, probe means where present),
 3. wall-time spans: per-chunk us/round with the compile chunk split out
-   from steady state, plus p50/p95 over the steady-state chunks,
-4. (opt-in, ``profile=True``) a roofline/HLO-cost section: the previously
-   idle ``launch.roofline`` + ``launch.hlo_costs`` analyses run against a
-   freshly compiled bench-scale SAFL scan chunk on the local backend --
-   trip-count-weighted FLOPs/bytes/collective bytes and the v5e roofline
-   time terms.  (The 512-device dry-run harness ``launch.dryrun`` is NOT
-   imported here: it forces a device count at import time, which must never
-   leak into a live session.)
+   from steady state, plus p50/p95 over the steady-state chunks.
 
 ``tools/obs_report.py`` is the CLI wrapper.
 """
@@ -123,73 +116,7 @@ def _span_lines(events: list[dict]) -> list[str]:
     return lines
 
 
-def _profile_lines() -> list[str]:
-    """Compile a bench-scale SAFL scan chunk locally and run the roofline /
-    trip-weighted HLO-cost analyses on it."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.adaptive import AdaConfig
-    from repro.core.packed import make_packing_plan
-    from repro.core.safl import SAFLConfig, init_safl, safl_round
-    from repro.core.sketch import SketchConfig
-    from repro.data import BigramLMData, LMDataConfig
-    from repro.launch import roofline
-    from repro.launch.driver import make_chunk_fn
-    from repro.models import ModelConfig, init_params, loss_fn
-    from repro.models.model import count_params_analytic
-
-    model = ModelConfig(name="obs-profile", arch_type="dense", num_layers=2,
-                        d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
-                        vocab_size=128)
-    clients, k, seq, bpc, rounds = 5, 2, 32, 10, 4
-    cfg = SAFLConfig(sketch=SketchConfig(kind="countsketch", ratio=0.05,
-                                         min_b=8),
-                     server=AdaConfig(name="amsgrad", lr=0.01),
-                     client_lr=0.5, local_steps=k, remat_local=False)
-    data = BigramLMData(LMDataConfig(vocab_size=model.vocab_size,
-                                     seq_len=seq, num_clients=clients))
-    sampler = data.device_sampler(bpc, k)
-    params = init_params(model, jax.random.key(0))
-    plan = make_packing_plan(cfg.sketch, params)
-    round_fn = functools.partial(safl_round, cfg,
-                                 lambda p, b: loss_fn(model, p, b), plan=plan)
-    chunk = make_chunk_fn(round_fn, sampler, rounds, donate=False)
-    compiled = chunk.lower(params, init_safl(cfg, params),
-                           sampler.init_state(), jax.random.key(0),
-                           jnp.asarray(0, jnp.int32)).compile()
-
-    n_active = count_params_analytic(model, active_only=True)
-    tokens = clients * k * (bpc // k) * seq * rounds
-    rep = roofline.analyze(
-        compiled, arch=model.name, shape=f"{rounds}r", mesh_name="local",
-        chips=max(1, jax.device_count()),
-        model_flops=6.0 * n_active * tokens,
-        note=f"bench-scale safl chunk ({rounds} rounds)")
-    lines = [
-        f"  program: {rounds}-round scanned SAFL chunk, bench model"
-        f" ({n_active/1e3:.0f}k params, sketch ratio {cfg.sketch.ratio})",
-        "  " + roofline.format_row(rep),
-        f"  flops/device(trip-weighted)={rep.flops_per_device:.3e}"
-        f"  hbm_bytes~{rep.bytes_per_device:.3e}"
-        f"  collective_bytes={rep.coll_bytes_per_device:.3e}",
-    ]
-    counts = rep.coll_breakdown.get("counts", {})
-    nz = {kk: v for kk, v in counts.items() if v}
-    if nz:
-        lines.append("  collectives: " +
-                     ", ".join(f"{kk}x{v}" for kk, v in sorted(nz.items())))
-    else:
-        lines.append("  collectives: none (single-device program)")
-    lines.append(f"  roofline constants: PEAK={roofline.PEAK_FLOPS:.0e}F/s"
-                 f" HBM={roofline.HBM_BW:.0e}B/s ICI={roofline.ICI_BW:.0e}B/s"
-                 " (v5e; rescale for other parts)")
-    return lines
-
-
-def render(run_dir: str, profile: bool = True) -> str:
+def render(run_dir: str) -> str:
     run = load_run(run_dir)
     out = [f"== telemetry run report: {run_dir} ==", "", "-- manifest --"]
     out += _manifest_lines(run["manifest"])
@@ -197,11 +124,4 @@ def render(run_dir: str, profile: bool = True) -> str:
     out += _metric_lines(run["rows"])
     out += ["", "-- wall-time spans --"]
     out += _span_lines(run["events"])
-    if profile:
-        out += ["", "-- roofline / HLO costs (freshly compiled, local"
-                " backend) --"]
-        try:
-            out += _profile_lines()
-        except Exception as e:  # report stays usable without the profile
-            out.append(f"  profile section unavailable: {e!r}")
     return "\n".join(out) + "\n"

@@ -1,15 +1,11 @@
 """Render a telemetry run directory as a text report (DESIGN.md §11).
 
-    python tools/obs_report.py RUN_DIR [--no-profile]
+    python tools/obs_report.py RUN_DIR
 
 Sections: manifest summary, per-key metric summary (last-wins over
 duplicate rounds from supervised retries), wall-time spans with the
-compile chunk split from steady state (p50/p95 per round), recovery
-events, and -- unless ``--no-profile`` -- the roofline/HLO-cost section,
-which compiles a bench-scale SAFL scan chunk on the local backend and runs
-the ``repro.launch.roofline`` + ``hlo_costs`` analyses on it (the
-previously idle DESIGN §6 tooling).  See ``repro.obs.report`` for the
-implementation.
+compile chunk split from steady state (p50/p95 per round), and recovery
+events.  See ``repro.obs.report`` for the implementation.
 """
 
 from __future__ import annotations
@@ -21,16 +17,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def main(argv: list[str]) -> int:
-    profile = "--no-profile" not in argv
-    paths = [a for a in argv if not a.startswith("--")]
-    if len(paths) != 1:
+    if len(argv) != 1:
         print(__doc__)
         return 2
-    if not os.path.isdir(paths[0]):
-        print(f"# not a run directory: {paths[0]}")
+    if not os.path.isdir(argv[0]):
+        print(f"# not a run directory: {argv[0]}")
         return 2
     from repro.obs.report import render
-    sys.stdout.write(render(paths[0], profile=profile))
+    sys.stdout.write(render(argv[0]))
     return 0
 
 
