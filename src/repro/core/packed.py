@@ -21,8 +21,10 @@ memory-bound pass.  This module adopts that design (DESIGN.md §4):
                             streams depend only on the folded key).
 * ``sk_packed``/``desk_packed`` -- fused single-jitted-pass sk/desk for all
                             three sketch families.  The default balanced
-                            count-sketch family is pure gather/reshape/sum
-                            (scatter-free; XLA-optimal, no kernel needed).
+                            count-sketch family scatters nothing: its sk
+                            adds each leaf's rows rotated by r_k
+                            (contiguous slices, no gather); its desk is
+                            still an element gather.
                             The "independent" family collapses the whole
                             tree to ONE segment-sum over a global hash
                             (leaf-local slot + payload offset); with
@@ -423,9 +425,11 @@ def sk_packed_clients(plan: PackingPlan, rp: dict, stacked: Pytree) -> jax.Array
 
     For the independent-hash CountSketch family with ``use_pallas`` this is
     ONE batched Pallas launch over a (client, b-block, tile) grid; all
-    other families (including the default balanced one, which is
-    scatter-free and needs no kernel) run as a vmap of the fused pass
-    (still one jitted dispatch for the whole tree, not per leaf).
+    other families run as a vmap of the fused pass (still one jitted
+    dispatch for the whole tree, not per leaf).  For the default balanced
+    family that pass is a loop over each leaf's rows, adding row k rotated
+    by r_k into a (G, b) accumulator: contiguous slices with the client
+    axis leading, no element gather.
     """
     cfg = plan.cfg
     flat2 = jax.vmap(lambda t: pack_tree(plan, t))(stacked)

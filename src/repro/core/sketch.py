@@ -63,8 +63,9 @@ class SketchConfig:
     #   "balanced"    -- block-sparse JL: pad to (m, b) rows, random per-row
     #                    rotation, sum rows.  Collision prob is 0 within a
     #                    row and exactly 1/b across rows, so Lemma A.3's
-    #                    variance bound carries; sk/desk are pure
-    #                    gather/reshape/sum (no scatter) -- the fast family.
+    #                    variance bound carries.  No scatter: sk adds the
+    #                    rotated rows (contiguous slices, no gather); desk
+    #                    is still an element gather -- the fast family.
     #   "independent" -- classic per-element uniform hash + segment-sum
     #                    (the seed reference implementation).
     cs_hash: str = "balanced"
@@ -232,15 +233,32 @@ def _balanced_cs_params(key: jax.Array, n: int, b: int):
     return r, s
 
 
+# jitted so that eager callers trace and compile the row loop once per shape
+@partial(jax.jit, static_argnums=3)
 def _balanced_sk_core(v: jax.Array, r: jax.Array, s: jax.Array, b: int) -> jax.Array:
-    """sk given derived (r, s): out[j] = sum_k x[k, (j - r_k) mod b] --
-    scatter-free gather + row-sum.  Shared by the per-leaf reference and the
-    packed engine (single source of truth for the index math)."""
-    n = v.shape[0]
-    m = r.shape[0]
-    x = jnp.pad(v * s.astype(v.dtype), (0, m * b - n)).reshape(m, b)
-    idx = (jnp.arange(b)[None, :] - r[:, None]) % b
-    return jnp.take_along_axis(x, idx, axis=1).sum(axis=0)
+    """sk given derived (r, s): out[j] = sum_k x[k, (j - r_k) mod b] with
+    x = v * s padded to (m, b) -- the sum of the m rows of the signed
+    vector, row k cyclically rotated right by r_k.  A row is a contiguous
+    slice of v and its rotation two contiguous reads: no element gather,
+    no (m, b) index array, and no padded copy of v (only the last, partial
+    row is padded).  Rows are added in ascending k whatever the batch, so
+    under a vmap over clients each client gets the bits it gets alone.
+    Shared by the per-leaf reference and the packed engine (single source
+    of truth for the index math)."""
+    n, m = v.shape[0], r.shape[0]
+    s = s.astype(v.dtype)
+
+    def add_row(k, acc):
+        row = (jax.lax.dynamic_slice_in_dim(v, k * b, b)
+               * jax.lax.dynamic_slice_in_dim(s, k * b, b))
+        return acc + jnp.roll(row, r[k])
+
+    acc = jnp.zeros((b,), v.dtype)
+    if m > 1:                       # rows 0 .. m-2 lie whole inside v
+        acc = jax.lax.fori_loop(0, m - 1, add_row, acc)
+    tail = (m - 1) * b
+    last = jnp.pad(v[tail:] * s[tail:], (0, m * b - n))
+    return acc + jnp.roll(last, r[m - 1])
 
 
 def _balanced_desk_core(u: jax.Array, r: jax.Array, s: jax.Array, n: int) -> jax.Array:

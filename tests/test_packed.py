@@ -8,6 +8,7 @@ side-of-the-round-trip).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -306,3 +307,124 @@ def test_safl_round_matches_per_leaf_composition():
     p2, _ = apply_update(cfg.server, init_safl(cfg, params), params, update)
     np.testing.assert_allclose(np.array(p1["W"]), np.array(p2["W"]),
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# balanced count-sketch: the row rotation against its two oracles
+# ---------------------------------------------------------------------------
+
+def _balanced_sk_gather(v, r, s, b):
+    """The element-gather form the row rotation replaced, kept as an oracle:
+    out[j] = sum_k x[k, (j - r_k) mod b] through take_along_axis."""
+    n, m = v.shape[0], r.shape[0]
+    x = jnp.pad(v * s.astype(v.dtype), (0, m * b - n)).reshape(m, b)
+    idx = (jnp.arange(b)[None, :] - r[:, None]) % b
+    return jnp.take_along_axis(x, idx, axis=1).sum(axis=0)
+
+
+def _balanced_sk_loop(v, r, s, b):
+    """NumPy loop over the rows, in float64: element (k, c) goes to slot
+    (c + r_k) mod b.  Returns the sketch and, per slot, the sum of the
+    magnitudes added there (for the float32 summation error bound)."""
+    v, r, s = (np.asarray(a, np.float64) for a in (v, r, s))
+    m = r.shape[0]
+    x = np.zeros(m * b)
+    x[:v.shape[0]] = v * s
+    out, mag = np.zeros(b), np.zeros(b)
+    for k in range(m):
+        slots = (np.arange(b) + int(r[k])) % b
+        np.add.at(out, slots, x[k * b:(k + 1) * b])
+        np.add.at(mag, slots, np.abs(x[k * b:(k + 1) * b]))
+    return out, mag
+
+
+# (n, b): n not a multiple of b with b < 128; n a multiple of b = 128; b not
+# a multiple of 128 above it; m = 1 exactly and with padding
+BAL_SHAPES = [(1000, 21), (4096, 128), (5000, 300), (700, 700), (650, 700)]
+
+
+@pytest.mark.parametrize("G", [1, 5, 16])
+@pytest.mark.parametrize("n,b", BAL_SHAPES)
+def test_balanced_sk_rotation_matches_gather_and_loop(n, b, G):
+    r, s = S._balanced_cs_params(jax.random.key(n + b), n, b)
+    m = r.shape[0]
+    V = jax.random.normal(jax.random.key(G), (G, n))
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda v: S._balanced_sk_core(v, r, s, b)))(V))
+    assert got.shape == (G, b)
+    gather = np.asarray(jax.vmap(lambda v: _balanced_sk_gather(v, r, s, b))(V))
+    eps = np.finfo(np.float32).eps
+    for g in range(G):
+        want, mag = _balanced_sk_loop(V[g], r, s, b)
+        # float32 summation of m terms, each rounded once from float64
+        bound = (m + 1) * eps * mag
+        assert np.all(np.abs(got[g] - want) <= bound)
+        assert np.all(np.abs(gather[g] - want) <= bound)
+        # one client alone, unbatched: the same bits as inside the batch
+        np.testing.assert_array_equal(
+            got[g], np.asarray(S._balanced_sk_core(V[g], r, s, b)))
+
+
+def test_balanced_sk_bitwise_across_batch_sizes():
+    """Each client's sketch is bit-identical whatever the batch it rides
+    in, as the streamed fold's microbatch >= G pins need."""
+    cfg = S.SketchConfig(kind="countsketch", ratio=0.05, min_b=16)
+    tree = {"w": jnp.zeros((37, 29)), "raw": jnp.zeros((7,)),
+            "e": jnp.zeros((3000,))}
+    plan = P.make_packing_plan(cfg, tree)
+    rp = P.derive_round_params(plan, jax.random.key(5))
+    big = jax.tree.map(lambda l: jax.random.normal(
+        jax.random.key(l.size), (16,) + l.shape), tree)
+    sk = jax.jit(lambda t: P.sk_packed_clients(plan, rp, t))
+    full = np.asarray(sk(big))
+    for G in (1, 5):
+        part = np.asarray(sk(jax.tree.map(lambda l: l[:G], big)))
+        np.testing.assert_array_equal(part, full[:G])
+
+
+@pytest.mark.parametrize("G", [1, 5, 16])
+def test_packed_balanced_sketch_matches_gather_oracle(G):
+    """The packed engine's client sketch (raw leaves included) against the
+    gather oracle leaf by leaf, under the vmap over G clients."""
+    cfg = S.SketchConfig(kind="countsketch", ratio=0.05, min_b=16)
+    tree = {"w": jnp.zeros((37, 29)), "raw": jnp.zeros((7,)),
+            "e": jnp.zeros((3000,)), "one": jnp.zeros((16,))}
+    plan = P.make_packing_plan(cfg, tree)
+    assert {op.raw for op in plan.ops} == {True, False}
+    rp = P.derive_round_params(plan, jax.random.key(G))
+    stacked = jax.tree.map(lambda l: jax.random.normal(
+        jax.random.key(l.size), (G,) + l.shape), tree)
+    got = np.asarray(P.sk_packed_clients(plan, rp, stacked))
+    flat = np.asarray(jax.vmap(lambda t: P.pack_tree(plan, t))(stacked))
+    for op in plan.ops:
+        v = flat[:, op.in_off:op.in_off + op.n]
+        seg = got[:, op.pay_off:op.pay_off + op.b]
+        if op.raw:
+            np.testing.assert_array_equal(seg, v)
+            continue
+        r, s = rp["bal"][op.index]
+        want = np.asarray(jax.vmap(
+            lambda x: _balanced_sk_gather(x, r, s, op.b))(jnp.asarray(v)))
+        np.testing.assert_allclose(seg, want, rtol=0, atol=1e-5)
+
+
+def test_balanced_sketch_lowers_without_gather():
+    """The client sketch compiles to slices and adds, with no gather op;
+    the desketch, still an element gather, keeps one."""
+    cfg = S.SketchConfig(kind="countsketch", ratio=0.05, min_b=16)
+    tree = {"w": jnp.zeros((40, 30)), "e": jnp.zeros((700,))}
+    plan = P.make_packing_plan(cfg, tree)
+    key = jax.random.key(0)
+    stacked = jax.tree.map(lambda l: jnp.zeros((5,) + l.shape), tree)
+
+    def hlo(f, *args):
+        return jax.jit(f).lower(*args).compile().as_text()
+
+    gather = re.compile(r"\sgather\(")
+    sk = hlo(lambda t, k: P.sk_packed_clients(
+        plan, P.derive_round_params(plan, k), t), stacked, key)
+    assert not gather.search(sk)
+    desk = hlo(lambda p, k: P.desk_packed(
+        plan, P.derive_round_params(plan, k), p),
+        jnp.zeros((plan.b_total,)), key)
+    assert gather.search(desk)
