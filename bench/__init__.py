@@ -4,5 +4,6 @@
 runs one cell of ``BENCHMARK.json``.  Configurations, traffic mixes, cells
 and per-layer metrics are files under ``bench/configs``, ``bench/traffic``,
 ``bench/workloads`` and ``bench/metrics``, found by the names in
-``BENCHMARK.json``.
+``BENCHMARK.json``; each configuration names its plain reference model,
+a module under ``bench/models``, in its ``reference`` key.
 """

@@ -272,7 +272,7 @@ def readings_of(r: dict) -> dict:
 
 def reference_readings(spec, seed: int, **faults) -> dict:
     import jax
-    from bench import reference, traffic, weights
+    from bench import models, reference, traffic, weights
     cfg = spec.config
     sampler = traffic.ZipfClients.from_traffic(
         spec.traffic, cfg["vocab_size"], weights.stream(seed, "data"))
@@ -281,8 +281,8 @@ def reference_readings(spec, seed: int, **faults) -> dict:
     batches = [sample(state, t)[1]["tokens"] for t in range(SETUP_ROUNDS)]
     rkey = weights.stream(seed, "rounds")
     keys = [jax.random.fold_in(rkey, t) for t in range(SETUP_ROUNDS)]
-    init = weights.make_init(reference.param_shapes(cfg),
-                             reference.dtype_of(cfg["dtype"]),
+    init = weights.make_init(models.of(cfg).param_shapes(cfg),
+                             weights.dtype_of(cfg["dtype"]),
                              cfg["num_layers"])
     return reference.run(cfg, spec.job, init(weights.stream(seed, "weights")),
                          batches, keys, weights.stream(seed, "probe"),
@@ -291,14 +291,14 @@ def reference_readings(spec, seed: int, **faults) -> dict:
 
 def _read_trace(spec, prog, trace_dir, rounds, dev, devs, run_dir):
     from bench import counts, devtrace, peaks, stages
-    files = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    fused = devtrace.reduce(devtrace.load(files[-1]))
+    trace_file = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[-1]
+    fused = devtrace.reduce(devtrace.load(trace_file))
     stage_s = None
     if any(m["name"] in stages.METRICS for m in spec.per_layer):
         stage_s = stages.measure(spec, prog, str(run_dir / "stages"))
     ctx = types.SimpleNamespace(
-        fused=fused, stages=stage_s, rounds=rounds, chips=len(devs),
-        peak=peaks.peak(devs[0].device_kind),
+        trace_file=trace_file, fused=fused, stages=stage_s, rounds=rounds,
+        chips=len(devs), peak=peaks.peak(devs[0].device_kind),
         flops_per_round=counts.flops_per_round(spec.config, spec.traffic),
         sketch_bytes=counts.sketch_bytes(spec.traffic["clients"],
                                          prog.plan.d_total,

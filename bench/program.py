@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, traffic, weights
+from bench import models, traffic, weights
 
 ROUNDS_FOREVER = 1 << 30
 # what the program does where it has no switch: a file may state such a key
@@ -37,13 +37,13 @@ def model_config(cfg: dict):
     listed = set(cfg.get("reduced", ())) | set(cfg.get("assumed", ()))
     over = {}
     for k, v in cfg.items():
-        if k in reference.META:
+        if k in models.META:
             continue
         if k in fields:
             over[k] = v
         elif k not in NO_SWITCH or v != NO_SWITCH[k] or k not in listed:
             raise ValueError(f"the program has no setting {k}={v!r}")
-    over["dtype"] = reference.dtype_of(cfg["dtype"])
+    over["dtype"] = weights.dtype_of(cfg["dtype"])
     return dataclasses.replace(base, **over)
 
 
@@ -60,12 +60,11 @@ def safl_config(job: dict, local_steps: int):
         client_lr=job["client_lr"], local_steps=local_steps)
 
 
-def check_layout(model_cfg, cfg: dict) -> None:
+def check_layout(model_cfg, want: dict) -> None:
     """The program's parameter layout must be the one the reference reads."""
     from repro.models.model import param_shapes
     got = jax.tree.map(tuple, param_shapes(model_cfg),
                        is_leaf=lambda x: isinstance(x, tuple))
-    want = reference.param_shapes(cfg)
     if got != want:
         raise RuntimeError("the program's parameter layout differs from the "
                            "reference's")
@@ -139,15 +138,16 @@ def build(spec, seed: int, *, dtype: str | None = None) -> Program:
     if spec.job["driver"] != "run_scan":
         raise ValueError(f"unknown driver {spec.job['driver']!r}")
     cfg = dict(spec.config, **({"dtype": dtype} if dtype else {}))
+    model = models.of(cfg)
     model_cfg = model_config(cfg)
-    reference.check_config(cfg)
-    check_layout(model_cfg, cfg)
+    model.check_config(cfg)
+    shapes = model.param_shapes(cfg)
+    check_layout(model_cfg, shapes)
     safl = safl_config(spec.job, spec.traffic["local_steps"])
     sampler = traffic.ZipfClients.from_traffic(
         spec.traffic, cfg["vocab_size"], weights.stream(seed, "data"))
     wkey, rkey = weights.stream(seed, "weights"), weights.stream(seed, "rounds")
-    init = weights.make_init(reference.param_shapes(cfg),
-                             reference.dtype_of(cfg["dtype"]),
+    init = weights.make_init(shapes, weights.dtype_of(cfg["dtype"]),
                              cfg["num_layers"])
     params = init(wkey)
     state = jax.jit(functools.partial(safl_mod.init_safl, safl))(params)

@@ -36,9 +36,7 @@ from __future__ import annotations
 
 import bisect
 import collections
-import glob
 import json
-import os
 import re
 import sys
 
@@ -343,18 +341,15 @@ def reduce(events, window=None) -> dict:
 # ---------------------------------------------------------------------------
 
 def _window_split(ctx):
-    """The split of this run's window trace, kept on ``ctx`` for the other
-    readers: the newest trace the harness wrote under its ``window``
-    directories, and only if its window is the one ``ctx.fused`` was
-    reduced from; else None."""
+    """The split of this run's window trace (``ctx.trace_file``, the file
+    the harness reduced into ``ctx.fused``), kept on ``ctx`` for the other
+    readers; None where there is no trace or its window is not the one
+    ``ctx.fused`` was reduced from."""
     if not hasattr(ctx, "scope_split"):
         ctx.scope_split = None
-        from bench import harness
-        files = glob.glob(str(harness.OUT / "*" / "window" / "**" /
-                              "*.xplane.pb"), recursive=True)
-        if files:
+        if ctx.trace_file:
             try:
-                split = reduce(load(max(files, key=os.path.getmtime)))
+                split = reduce(load(ctx.trace_file))
             except ValueError:          # no device op in the window
                 split = None
             if split and split["window_s"] == ctx.fused["window_s"]:

@@ -18,6 +18,10 @@ import jax.numpy as jnp
 ZEROS = ("bias", "bi", "bo", "bq", "bk", "bv")
 
 
+def dtype_of(name: str):
+    return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
+
+
 def base_key(seed: int):
     """A key from any non-negative seed, all of its bits kept."""
     if seed < 0:

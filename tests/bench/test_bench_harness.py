@@ -2,6 +2,7 @@
 machine without the chip, and ``correct`` coming out false when the timed
 path is broken underneath or replaced by the control."""
 
+import hashlib
 import json
 import os
 import shutil
@@ -10,6 +11,7 @@ import sys
 import time
 
 import jax
+import numpy as np
 import pytest
 
 import benchpath  # noqa: F401
@@ -29,6 +31,9 @@ def tiny_spec():
     spec.traffic.update(clients=2, local_steps=2, seqs_per_step=2,
                         seq_len=32)
     return spec
+
+
+PINNED = ROOT / "tests/bench/data/bert_tiny_reference.json"
 
 
 def run_tiny(seconds=0.5):
@@ -140,8 +145,40 @@ def test_control_and_planted_fault_fail_their_limits():
     ("program", "mlp_output_bias", True), ("program", "no_such_key", 1),
     ("reference", "attn_bias", True)])
 def test_a_config_key_the_program_does_not_run_is_refused(side, key, value):
-    from bench import program, reference
+    from bench import models, program
     check = {"program": program.model_config,
-             "reference": reference.check_config}[side]
+             "reference": lambda c: models.of(c).check_config(c)}[side]
     with pytest.raises(ValueError):
         check(dict(tiny_spec().config, **{key: value}))
+
+
+def test_reference_readings_weights_and_tokens_are_pinned():
+    """The reference's readings at ``tiny_spec``'s size, and digests of its
+    weights and its three rounds' tokens, equal to the last bit what the
+    reference gave before its model moved into ``bench/models``."""
+    from bench import models, traffic, weights
+    pinned = json.loads(PINNED.read_text())
+    spec = tiny_spec()
+    cfg = spec.config
+    ref = harness.reference_readings(spec, SEED)
+    ref.pop("seconds")
+    assert {k: np.asarray(v, np.float64).tolist() for k, v in ref.items()} \
+        == {k: pinned[k] for k in ref}
+    init = weights.make_init(models.of(cfg).param_shapes(cfg),
+                             weights.dtype_of(cfg["dtype"]),
+                             cfg["num_layers"])
+    params = init(weights.stream(SEED, "weights"))
+    assert _sha256(jax.tree.leaves(params)) == pinned["weights_sha256"]
+    sampler = traffic.ZipfClients.from_traffic(
+        spec.traffic, cfg["vocab_size"], weights.stream(SEED, "data"))
+    sample = jax.jit(sampler.sample)
+    tokens = [sample(sampler.init_state(), t)[1]["tokens"]
+              for t in range(harness.SETUP_ROUNDS)]
+    assert _sha256(tokens) == pinned["tokens_sha256"]
+
+
+def _sha256(arrays) -> str:
+    h = hashlib.sha256()
+    for x in arrays:
+        h.update(np.asarray(x).tobytes())
+    return h.hexdigest()

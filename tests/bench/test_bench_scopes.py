@@ -1,6 +1,7 @@
 """The split of a traced window by stage scope, and the metrics that read it."""
 
 import json
+import os
 import types
 
 import pytest
@@ -84,9 +85,9 @@ def test_an_op_outliving_the_one_that_encloses_it():
     assert scopes._exclusive(ivs) == {"a": 10, "b": 35, "c": 5}
 
 
-def _ctx(window_s, rounds=2):
+def _ctx(window_s, rounds=2, trace_file=None):
     return types.SimpleNamespace(fused={"window_s": window_s},
-                                 rounds=rounds)
+                                 rounds=rounds, trace_file=trace_file)
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_METRICS))
@@ -102,7 +103,7 @@ def test_span_metric_has_an_entry_and_reads_its_scope(name, monkeypatch):
 
 
 @pytest.mark.parametrize("name", sorted(SPAN_METRICS))
-def test_span_metric_is_none_without_its_scope(name, monkeypatch, tmp_path):
+def test_span_metric_is_none_without_its_scope(name, monkeypatch):
     reader = harness.metric_reader(name)
     # a program without scopes: every op unscoped
     split = {"window_s": 1.0, "scopes": {scopes.UNSCOPED: 0.9}}
@@ -110,24 +111,29 @@ def test_span_metric_is_none_without_its_scope(name, monkeypatch, tmp_path):
     assert reader.read(_ctx(1.0)) is None
     # no trace at all
     monkeypatch.undo()
-    monkeypatch.setattr(harness, "OUT", tmp_path)
     assert reader.read(_ctx(1.0)) is None
 
 
 def test_window_trace_is_this_runs(monkeypatch, tmp_path):
-    """The readers take the newest window trace, and only where its window
-    is the one the harness reduced."""
-    trace = tmp_path / "cell.7" / "window" / "plugins" / "x.xplane.pb"
-    trace.parent.mkdir(parents=True)
-    trace.write_bytes(b"")
+    """The readers take the trace the harness names in ``ctx``, not the
+    newest one on disk (another cell's), and only where its window is the
+    one the harness reduced."""
+    ours, other = (tmp_path / f"{cell}.7" / "window" / "x.xplane.pb"
+                   for cell in ("ours", "other"))
+    for trace in (ours, other):
+        trace.parent.mkdir(parents=True)
+        trace.write_bytes(b"")
+    os.utime(other, (ours.stat().st_mtime + 60,) * 2)
     monkeypatch.setattr(harness, "OUT", tmp_path)
-    monkeypatch.setattr(scopes, "load", lambda path: _synthetic())
-    ctx = _ctx(100e-6)
+    monkeypatch.setattr(scopes, "load", lambda path: _synthetic()
+                        if path == ours else [])
+    ctx = _ctx(100e-6, trace_file=ours)
     assert scopes.ms_per_round(ctx, "safl.client") == \
         pytest.approx(1e3 * 25e-6 / 2)
     # kept for the other readers: busy 70 and 40 us on the two devices
     assert ctx.scope_split["busy_s"] == pytest.approx(55e-6)
-    assert scopes.ms_per_round(_ctx(99e-6), "safl.client") is None
+    assert scopes.ms_per_round(_ctx(99e-6, trace_file=ours),
+                               "safl.client") is None
 
 
 # a cut of a window traced on a TPU v5e in bert100m-nob2.fed5.local4, with
